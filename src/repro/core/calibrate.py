@@ -2,15 +2,16 @@
 persist the throughput tables + memory model (paper §III-C protocol).
 
 The paper's stance is per-device profiling ("for newer devices we rerun the
-full data-collection on the target hardware").  Here the measurable device is
-the CPU host; the same driver would run unchanged on a TPU worker.
+full data-collection on the target hardware").  The same code runs on the
+CPU host (Pallas kernels in interpret mode) and on a TPU (kernels compiled
+by Mosaic); ``device_name`` keys the tables by the device that ran them.
 
 Collected kernel families (each a selection-oracle candidate, core/oracle.py):
   - matmul|xla_default@<m0>x<n0>      (the framework's jnp/einsum path, one
                                        table per reference grid), fp32 + bf16
   - bmm|xla_default@<b0>x<m0>x<n0>    (batched, one table per reference grid)
   - attention|fa_jnp                  (the model stack's flash-attention path)
-  - matmul|mm_<cfg>                   (Pallas interpret kernels - Table VI)
+  - matmul|mm_<cfg>                   (Pallas kernels - Table VI)
   - attention|fa_<cfg>                (Pallas flash attention, per dtype)
   - memory model                      (utility ops, linear regression)
 """
@@ -24,18 +25,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import devices as D
 from repro.core import memory_model as mm
 from repro.core import profiler
 from repro.core.table import KernelKey, TableStore, ThroughputTable
 from repro.kernels import flash_attention as fkern
 from repro.kernels import matmul as mkern
+from repro.kernels.ops import interpret_default
 from repro.models import attention as A
 
 DEFAULT_K_ANCHORS = (32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)
 
 
 def device_name() -> str:
-    return f"{jax.default_backend()}_host"
+    """``cpu_host`` on the CPU; on an accelerator, its ``device_kind`` as a
+    name (``tpu_v5_lite`` on a v5e), which must have a registered profile."""
+    if jax.default_backend() == "cpu":
+        return D.CPU_HOST
+    return D.accelerator_name(jax.devices()[0].device_kind)
 
 
 def _table_from_measurements(key, anchors_dur, m0, n0, batch=1,
@@ -128,7 +135,7 @@ def calibrate_pallas_matmul(store: TableStore, configs=None, *,
                             dtype=jnp.float32,
                             k_anchors=(128, 256, 512, 1024, 2048),
                             verbose=False):
-    """Interpret-mode Pallas kernels: each BlockSpec config is its own
+    """Pallas kernels: each BlockSpec config is its own
     kernel with its own table (kernel differentiation, Table VI).  The
     reference grid is PROPORTIONAL to the block config (2x2 tiles), so the
     selection oracle's nearest-grid rule can tell the configs apart — a
@@ -139,7 +146,8 @@ def calibrate_pallas_matmul(store: TableStore, configs=None, *,
     for cfg in configs:
         m0 = 2 * cfg.bm
         n0 = 2 * cfg.bn
-        f = jax.jit(lambda a, b: mkern.matmul_kernel(a, b, cfg, interpret=True))
+        f = jax.jit(lambda a, b: mkern.matmul_kernel(
+            a, b, cfg, interpret=interpret_default()))
         durs = {}
         for k in k_anchors:
             kk = max(k, cfg.bk)
@@ -166,7 +174,7 @@ def calibrate_pallas_attention(store: TableStore, configs=None, *,
         dt = jnp.dtype(dtype)
         for cfg in configs:
             f = jax.jit(lambda q, k, v: fkern.flash_attention_kernel(
-                q, k, v, cfg, causal=True, interpret=True))
+                q, k, v, cfg, causal=True, interpret=interpret_default()))
             durs, anchors = {}, {}
             bh, hd = 4, 64
             for s in s_anchors:
@@ -209,7 +217,7 @@ def calibrate_host(path: Optional[str] = None, *, dtypes=("float32",),
         calibrate_attention(store, dtype=dt, verbose=verbose)
     if pallas:
         if verbose:
-            print("[calibrate] pallas interpret kernels")
+            print("[calibrate] pallas kernels")
         calibrate_pallas_matmul(store, verbose=verbose)
         calibrate_pallas_attention(store, dtypes=dtypes, verbose=verbose)
     if verbose:

@@ -282,7 +282,8 @@ def p2p_time(nbytes: float, ic: Interconnect) -> float:
 # ---------------------------------------------------------------------------
 
 def interconnect_for(device: Optional[str]) -> Interconnect:
-    """The interconnect of a registered device, ``DEFAULT_INTERCONNECT`` for
+    """The interconnect of a registered device (a calibrated accelerator's
+    chip before its profile is registered), ``DEFAULT_INTERCONNECT`` for
     unknown/unregistered names (or profiles that predate the field)."""
     if device is None:
         return DEFAULT_INTERCONNECT
@@ -290,7 +291,7 @@ def interconnect_for(device: Optional[str]) -> Interconnect:
     try:
         prof = D.get_profile(device)
     except KeyError:
-        return DEFAULT_INTERCONNECT
+        prof = D.chip_profile(device)
     return getattr(prof, "interconnect", None) or DEFAULT_INTERCONNECT
 
 

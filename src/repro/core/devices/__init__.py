@@ -10,11 +10,13 @@ from __future__ import annotations
 
 from typing import Dict, List
 
-from repro.core.devices.host import host_profile_from_store
+from repro.core.devices.host import (CPU_HOST, accelerator_name, chip_profile,
+                                     host_profile_from_store)
 from repro.core.devices.profiles import FLEET, DeviceProfile
 
 __all__ = ["DeviceProfile", "register", "get_profile", "list_devices",
-           "host_profile_from_store", "REGISTRY"]
+           "host_profile_from_store", "accelerator_name", "chip_profile",
+           "CPU_HOST", "REGISTRY"]
 
 REGISTRY: Dict[str, DeviceProfile] = {p.name: p for p in FLEET}
 

@@ -63,6 +63,7 @@ class StragglerMonitor:
 class TrainLog:
     steps: List[int] = dataclasses.field(default_factory=list)
     losses: List[float] = dataclasses.field(default_factory=list)
+    step_seconds: List[float] = dataclasses.field(default_factory=list)
     restarts: int = 0
     straggler_events: int = 0
 
@@ -92,11 +93,13 @@ def run_training(*, step_fn: Callable, init_state, data, num_steps: int,
                 injector.maybe_fail(step)
             batch = data.batch_at(step)
             state, metrics = step_fn(state, batch)
+            loss = float(metrics["loss"])   # waits for the step to finish
             dt = time.perf_counter() - t0
             if monitor is not None and monitor.observe(step, dt):
                 log.straggler_events += 1
             log.steps.append(step)
-            log.losses.append(float(metrics["loss"]))
+            log.losses.append(loss)
+            log.step_seconds.append(dt)
             if step % ckpt_every == 0:
                 store.save(step, state)
             step += 1

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import registry as cr
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import registry as mr
 from repro.serving.engine import Request, ServingEngine
 
@@ -59,5 +60,10 @@ def parse_args(argv=None):
     return ap.parse_args(argv)
 
 
+def main(argv=None) -> dict:
+    enable_compile_cache()
+    return run(parse_args(argv))
+
+
 if __name__ == "__main__":
-    run(parse_args())
+    main()

@@ -30,6 +30,8 @@ from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.distributed import sharding as sh
 from repro.distributed import specs as sp
 from repro.ft import driver as ftd
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models import registry as mr
 from repro.training import optimizer as opt
 from repro.training import step as tstep
@@ -46,9 +48,7 @@ def maybe_init_distributed():
 def build_mesh(spec: str):
     """spec 'dxm' e.g. '2x2'; '1x1' -> single device mesh."""
     d, m = (int(x) for x in spec.split("x"))
-    n = len(jax.devices())
-    assert d * m <= n, f"need {d*m} devices, have {n}"
-    return jax.make_mesh((d, m), ("data", "model"))
+    return make_host_mesh(d, m)
 
 
 def run(args) -> dict:
@@ -100,6 +100,7 @@ def run(args) -> dict:
         wall = time.time() - t0
 
     result = {"losses": log.losses, "steps": log.steps,
+              "step_s": log.step_seconds,
               "restarts": log.restarts, "wall_s": wall,
               "straggler_events": log.straggler_events,
               "final_loss": log.losses[-1] if log.losses else float("nan"),
@@ -135,5 +136,10 @@ def parse_args(argv=None):
     return ap.parse_args(argv)
 
 
+def main(argv=None) -> dict:
+    enable_compile_cache()
+    return run(parse_args(argv))
+
+
 if __name__ == "__main__":
-    run(parse_args())
+    main()

@@ -33,6 +33,7 @@ def test_train_on_2x4_mesh_matches_single_device():
     from repro.distributed import sharding as sh, specs as sp
     from repro.training import optimizer as opt, step as tstep
     from repro.data.pipeline import DataConfig, SyntheticLM
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     import dataclasses
 
@@ -44,7 +45,7 @@ def test_train_on_2x4_mesh_matches_single_device():
     adamw = opt.AdamWConfig(lr=1e-3)
 
     def run(mesh_shape):
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
         with sh.mesh_context(mesh):
             params = model.init(jax.random.key(0))
             o = opt.init_opt_state(params)
@@ -119,10 +120,11 @@ def test_sharded_decode_step_lowered_on_mesh():
     from repro.configs import registry as cr
     from repro.models import registry as mr
     from repro.distributed import sharding as sh, specs as sp
+    from repro.launch.mesh import make_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     cfg = dataclasses.replace(cr.reduced("yi-6b", n_layers=2), compute_dtype="float32")
     model = mr.build(cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     with sh.mesh_context(mesh):
         params = model.abstract_params()
         cache = model.abstract_cache(8, 64, dtype=jnp.float32)

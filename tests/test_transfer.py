@@ -1,7 +1,9 @@
 """Roofline-ratio transfer invariants (core/transfer.py), the device
-registry, and the DeviceModel/DeviceProfile strict-dtype peak lookup.
-All synthetic — no jax, no calibration artifact."""
+registry, the calibrated device's description, and the
+DeviceModel/DeviceProfile strict-dtype peak lookup.  All synthetic — no
+calibration artifact."""
 import dataclasses
+import os
 
 import numpy as np
 import pytest
@@ -179,6 +181,76 @@ def test_tpu_v5e_profile_mirrors_device_model():
     assert p.peak_flops == m.peak_flops
     assert (p.hbm_bw, p.hbm_bytes, p.smem_bytes, p.link_bw) == \
         (m.hbm_bw, m.hbm_bytes, m.vmem_bytes, m.ici_bw)
+
+
+# ---------------------------------------------------------------------------
+# the calibrated device's description
+# ---------------------------------------------------------------------------
+
+def calibrated_store(device):
+    st = TableStore()
+    st.add(mm_table(device))
+    st.memory_model = {"coef": [1e-11, 0.0, 0.0, 1e-6], "train_rel_err": 0.0,
+                       "class_coef": {}}
+    st.meta = {"device": device}
+    return st
+
+
+def test_device_name_is_cpu_host_on_cpu():
+    from repro.core import calibrate
+    assert calibrate.device_name() == "cpu_host"
+
+
+def test_calibrated_v5e_takes_sizes_and_interconnect_from_its_chip():
+    from repro.core.collectives import interconnect_for
+    name = D.accelerator_name("TPU v5 lite")
+    chip = D.get_profile("tpu_v5e")
+    # collectives price over the chip's ICI even before registration
+    assert name not in D.REGISTRY
+    assert interconnect_for(name) == chip.interconnect
+    p = D.host_profile_from_store(calibrated_store(name))
+    assert p.name == name and p.kind == "tpu"
+    assert p.hbm_bytes == 16 * 1024 ** 3 and p.smem_bytes == chip.smem_bytes
+    assert p.interconnect == chip.interconnect
+    # what the calibration measured is kept
+    assert p.peak_flops == {"float32": 6e11}
+    assert p.hbm_bw == pytest.approx(1e11)
+
+
+def test_uncalibrated_or_unknown_accelerator_raises():
+    with pytest.raises(KeyError, match="no device profile"):
+        D.accelerator_name("TPU v99")
+    with pytest.raises(ValueError, match="not a calibrated device"):
+        D.host_profile_from_store(calibrated_store("gpu_host"))
+    # an accelerator never falls back to assumed peaks or bandwidth
+    empty = TableStore()
+    empty.meta = {"device": D.accelerator_name("TPU v5 lite")}
+    with pytest.raises(ValueError, match="recalibrate"):
+        D.host_profile_from_store(empty)
+    # the CPU host keeps its fallback
+    assert D.host_profile_from_store(TableStore()).kind == "cpu"
+
+
+def test_compile_cache_follows_env_else_fixed_in_tree_path(monkeypatch):
+    import jax
+    from repro.launch import compile_cache as cc
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    prev = {k: getattr(jax.config, k) for k in keys}
+    try:
+        monkeypatch.setenv(cc.CACHE_ENV, "/elsewhere")
+        assert cc.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == prev[keys[0]]
+        monkeypatch.delenv(cc.CACHE_ENV)
+        first = cc.enable_compile_cache()
+        assert first == cc.enable_compile_cache() == cc.DEFAULT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == first
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(root, ".jax_compilation_cache")
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
 
 
 def test_transfer_store_rekeys_and_drops_foreign_tables():
