@@ -297,7 +297,10 @@ def kv_cache_bytes(cfg: C.ModelConfig, batch: int, ctx: int,
 @functools.lru_cache(maxsize=4096)
 def _snippet_features(snippet: str, shape: tuple, dtype: str) -> Dict[str, float]:
     fn = SNIPPETS[snippet]
-    compiled = jax.jit(fn).lower(_sds(shape, dtype)).compile()
+    with jax.profiler.TraceAnnotation("predict.snippet_compile",
+                                      snippet=snippet,
+                                      shape="x".join(map(str, shape))):
+        compiled = jax.jit(fn).lower(_sds(shape, dtype)).compile()
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0]

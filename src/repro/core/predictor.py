@@ -29,6 +29,19 @@ class PredictionRow:
     kernel: str
 
 
+def seconds_by_kind(rows) -> dict:
+    """Predicted seconds per op family, each summed in row order:
+    ``matmul`` (matmul and bmm rows: the throughput tables), ``attention``
+    (the attention tables; decode attention the memory model), ``memory``
+    (the memory model) and ``collective`` (the interconnect model), the
+    last only when there are collectives."""
+    out = {"matmul": 0.0, "attention": 0.0, "memory": 0.0}
+    for r in rows:
+        kind = "matmul" if r.kind == "bmm" else r.kind
+        out[kind] = out.get(kind, 0.0) + r.seconds
+    return out
+
+
 class PM2Lat:
     def __init__(self, store: TableStore, device: str):
         self.store = store

@@ -28,6 +28,10 @@ from repro.models import layers as L
 import os
 DEFAULT_KV_BLOCK = int(os.environ.get("REPRO_KV_BLOCK", "256"))
 NEG_INF = -1e30
+# Name scope of the attention core (scores, softmax, values; forward and
+# backward): compiled instructions carry it in their ``op_name`` metadata,
+# so a device trace can tell attention ops from the projections around them.
+SCOPE = "attention"
 
 
 class AttnSpec(NamedTuple):
@@ -190,7 +194,9 @@ def _fa_bwd_fused(q, k, v, o, lse, do, q_offset, spec, kv_len):
     kernel whose internals never touch HBM; the custom_vjp wrapper makes
     core/jaxpr_cost account it that way (call-boundary I/O only)."""
     nb = k.shape[1] // min(spec.kv_block, k.shape[1])
-    dq, dk, dv = _fa_bwd_scan(q, k, v, o, lse, do, q_offset, spec, 0, nb, kv_len)
+    with jax.named_scope(SCOPE):
+        dq, dk, dv = _fa_bwd_scan(q, k, v, o, lse, do, q_offset, spec, 0, nb,
+                                  kv_len)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -253,10 +259,11 @@ def flash_attention(q, k, v, *, spec: AttnSpec, q_offset: int = 0):
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         kv_len = Skv
     qg = q.reshape(B, Sq, Hkv, G, hd)
-    if spec.causal_block_skip and spec.causal and Sq % blk == 0 and not pad:
-        o = _flash_attn_causal_skip(qg, k, v, q_offset, spec)
-    else:
-        o = _flash_attn(qg, k, v, q_offset, spec, kv_len)
+    with jax.named_scope(SCOPE):
+        if spec.causal_block_skip and spec.causal and Sq % blk == 0 and not pad:
+            o = _flash_attn_causal_skip(qg, k, v, q_offset, spec)
+        else:
+            o = _flash_attn(qg, k, v, q_offset, spec, kv_len)
     return o.reshape(B, Sq, Hq, hd)
 
 
@@ -275,17 +282,18 @@ def decode_attention(q, k_cache, v_cache, slot_positions, pos, window=None):
     B, _, Hq, hd = q.shape
     Hkv = k_cache.shape[2]
     G = Hq // Hkv
-    qg = q.reshape(B, Hkv, G, hd).astype(k_cache.dtype)
-    scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
-    s = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache,
-                   preferred_element_type=jnp.float32) * scale
-    valid = (slot_positions >= 0) & (slot_positions <= pos)
-    if window is not None:
-        valid &= slot_positions > pos - window
-    s = jnp.where(valid[None, None, None, :], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgk,bkhd->bhgd", p.astype(v_cache.dtype), v_cache,
-                   preferred_element_type=jnp.float32)
+    with jax.named_scope(SCOPE):
+        qg = q.reshape(B, Hkv, G, hd).astype(k_cache.dtype)
+        scale = 1.0 / jnp.sqrt(hd).astype(jnp.float32)
+        s = jnp.einsum("bhgd,bkhd->bhgk", qg, k_cache,
+                       preferred_element_type=jnp.float32) * scale
+        valid = (slot_positions >= 0) & (slot_positions <= pos)
+        if window is not None:
+            valid &= slot_positions > pos - window
+        s = jnp.where(valid[None, None, None, :], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhgk,bkhd->bhgd", p.astype(v_cache.dtype), v_cache,
+                       preferred_element_type=jnp.float32)
     return o.reshape(B, 1, Hq, hd).astype(q.dtype)
 
 

@@ -23,17 +23,25 @@ feasible ``TrainingPlan`` — cached point-by-point under the same keys as
 from __future__ import annotations
 
 import dataclasses
+import functools
+import itertools
 from typing import Optional, Sequence, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
+from repro.core import opgraph
 from repro.core.batch_predict import (BatchPredictor, PredictionCache,
                                       config_key)
+from repro.core.predictor import seconds_by_kind
 
 
 @dataclasses.dataclass
 class LatencyQueryResult:
+    """One forward-pass latency.  ``kind_seconds`` splits ``seconds`` by op
+    family (``predictor.seconds_by_kind``); None when the answer came from
+    the cache, which keeps the total only."""
     model: str
     device: str
     dtype: str
@@ -41,6 +49,7 @@ class LatencyQueryResult:
     seq: int
     seconds: float
     cached: bool
+    kind_seconds: Optional[dict] = None
 
     def to_json(self) -> dict:
         return dataclasses.asdict(self)
@@ -94,7 +103,11 @@ class TrainLatencyResult(_CommShareMixin):
     """One TRAINING step (fwd + bwd + gradient comm + optimizer update)
     under a parallelism strategy: schedule makespan plus the busy-time
     split.  ``exposed_comm_seconds`` is the communication/bubble time not
-    hidden behind compute — the overlap-planning signal."""
+    hidden behind compute — the overlap-planning signal.  ``kind_seconds``
+    splits the schedule's work by op family (``predictor.seconds_by_kind``):
+    it sums to the sequential work, which is ``seconds`` when nothing
+    overlaps (one device, one microbatch); None for an entry that
+    ``sweep_train`` wrote."""
     model: str
     device: str
     dtype: str
@@ -117,6 +130,7 @@ class TrainLatencyResult(_CommShareMixin):
     cached: bool = False
     schedule: str = "gpipe"
     peak_bytes: float = 0.0
+    kind_seconds: Optional[dict] = None
 
 
 @dataclasses.dataclass
@@ -237,6 +251,26 @@ def _sched_entry(sched) -> dict:
             "max_stream_busy": max(busy.values()) if busy else 0.0}
 
 
+# cache-entry key prefix of a training answer's per-family seconds
+_KIND = "kind_seconds."
+
+
+def _answer_span(method):
+    """Wraps one answering method in the profiler span
+    ``latency.<method>``, tagged with the service's ``query_id`` (one more
+    per call), ``batch``, ``seq`` and, once answered, ``cached``."""
+    name = f"latency.{method.__name__}"
+
+    @functools.wraps(method)
+    def answer(self, model, batch, seq, *args, **kwargs):
+        with TraceAnnotation(name, query_id=next(self._query_ids),
+                             batch=int(batch), seq=int(seq)) as span:
+            result = method(self, model, batch, seq, *args, **kwargs)
+            span.set_metadata(cached=result.cached)
+            return result
+    return answer
+
+
 class LatencyService:
     def __init__(self, store=None, device: Optional[str] = None, *,
                  cache_path: Optional[str] = None, cache_size: int = 65536):
@@ -247,6 +281,7 @@ class LatencyService:
         self.device = device
         self.cache = PredictionCache(maxsize=cache_size, path=cache_path)
         self.predictor = BatchPredictor(store, device, cache=self.cache)
+        self._query_ids = itertools.count(1)
 
     def _resolve(self, model: Union[str, ModelConfig]) -> ModelConfig:
         if isinstance(model, ModelConfig):
@@ -254,6 +289,7 @@ class LatencyService:
         from repro.configs import registry
         return registry.get_any(model)
 
+    @_answer_span
     def latency_query(self, model: Union[str, ModelConfig], batch: int,
                       seq: int, dtype: Optional[str] = None,
                       device: Optional[str] = None) -> LatencyQueryResult:
@@ -272,10 +308,11 @@ class LatencyService:
             return LatencyQueryResult(cfg.name, pred.device,
                                       dtype or "float32", int(batch),
                                       int(seq), hit, cached=True)
-        seconds, _ = pred.predict_model(cfg, batch, seq, dtype=dtype)
+        seconds, rows = pred.predict_model(cfg, batch, seq, dtype=dtype)
         self.cache.put(key, seconds)
         return LatencyQueryResult(cfg.name, pred.device, dtype or "float32",
-                                  int(batch), int(seq), seconds, cached=False)
+                                  int(batch), int(seq), seconds, cached=False,
+                                  kind_seconds=seconds_by_kind(rows))
 
     def latency_grid(self, model: Union[str, ModelConfig],
                      batches: Sequence[int], seqs: Sequence[int],
@@ -294,6 +331,7 @@ class LatencyService:
                                              dtype, b, s), float(grid[i, j]))
         return grid
 
+    @_answer_span
     def latency_parallel(self, model: Union[str, ModelConfig], batch: int,
                          seq: int, dp: int = 1, tp: int = 1, pp: int = 1,
                          act_mode: str = "tp", microbatches: int = 1,
@@ -343,6 +381,7 @@ class LatencyService:
         self.cache.put(key, d)
         return result(d, False)
 
+    @_answer_span
     def latency_train(self, model: Union[str, ModelConfig], batch: int,
                       seq: int, dp: int = 1, tp: int = 1, pp: int = 1,
                       act_mode: str = "tp", microbatches: int = 1,
@@ -376,7 +415,9 @@ class LatencyService:
                 optimizer_seconds=d["optimizer_seconds"],
                 exposed_comm_seconds=d["exposed_comm_seconds"],
                 cached=cached, schedule=schedule,
-                peak_bytes=d.get("peak_bytes", 0.0))
+                peak_bytes=d.get("peak_bytes", 0.0),
+                kind_seconds={k[len(_KIND):]: v for k, v in d.items()
+                              if k.startswith(_KIND)} or None)
 
         key = PredictionCache.make_key(
             config_key(cfg), pred.cache_device, dtype, batch, seq,
@@ -403,6 +444,9 @@ class LatencyService:
         d.update(fwd_seconds=fwd, bwd_seconds=bwd, optimizer_seconds=opt,
                  peak_bytes=S.peak_memory_bytes(cfg, batch, seq, spec,
                                                 train=train, dtype=dtype))
+        # the cache keeps flat numbers: one key per family
+        d.update({_KIND + k: v
+                  for k, v in seconds_by_kind(sched.rows).items()})
         self.cache.put(key, d)
         return result(d, False)
 
@@ -924,4 +968,8 @@ class LatencyService:
 
     @property
     def stats(self) -> dict:
-        return self.cache.stats
+        """The cache's counts, and ``snippet_compiles``: the memory snippets
+        compiled so far, the calls ``opgraph._snippet_features``' cache did
+        not answer (process-wide, as that cache is)."""
+        return dict(self.cache.stats, snippet_compiles=(
+            opgraph._snippet_features.cache_info().misses))

@@ -5,7 +5,9 @@ pipeline bubble shrinking with microbatches, bucketed gradient-comm
 overlap in the training step, MoE all-to-all payloads, spec-keyed
 prediction caching, and the docs/parallelism.md overlap worked example."""
 import dataclasses
+import functools
 import math
+import operator
 
 import numpy as np
 import pytest
@@ -78,12 +80,19 @@ def test_training_spec_validation_and_tag():
 # the simulator
 # ---------------------------------------------------------------------------
 
+def _fold(values):
+    """Left-to-right float addition.  Python's ``sum`` compensates its
+    rounding since 3.12, so it is not the sequence of additions a chain
+    schedule makes."""
+    return functools.reduce(operator.add, values, 0.0)
+
+
 def test_simulate_chain_is_bitwise_sum():
     durs = [0.1, 0.0301, 7e-5, 0.42, 1e-9]
     streams = ["compute"] * 5
     deps = [()] + [(i,) for i in range(4)]
     _, ends, makespan = S.simulate(durs, streams, deps)
-    assert makespan == sum(durs)            # same additions, same order
+    assert makespan == _fold(durs)          # same additions, same order
     assert float(ends[-1]) == makespan
 
 
@@ -126,11 +135,11 @@ def test_no_overlap_schedule_equals_sequential_sum(bp):
     for spec in (og.ParallelismSpec(tp=4), og.ParallelismSpec(pp=2),
                  og.ParallelismSpec(dp=2, tp=2, pp=2, act_mode="sp")):
         total, rows = bp.predict_parallel(cfg, 4, 32, spec)
-        assert total == sum(r.seconds for r in rows)
+        assert total == _fold(r.seconds for r in rows)
         flat = og.enumerate_parallel_ops(cfg, 4, 32, spec)
         assert [r.name for r in rows] == [o.name for o in flat]
         s_total, s_rows = scalar.predict_parallel(cfg, 4, 32, spec)
-        assert s_total == sum(r.seconds for r in s_rows)
+        assert s_total == _fold(r.seconds for r in s_rows)
 
 
 def test_makespan_bounds_across_swept_configs(bp):

@@ -69,3 +69,33 @@ def test_pallas_flash_attention_compiles_for_v5e(one_chip, head_dim):
                              sharding=one_chip)
     _compile(lambda q, k, v: fk.flash_attention_kernel(q, k, v, cfg,
                                                        causal=True), q, q, q)
+
+
+def test_attention_scope_reaches_the_chips_products(one_chip):
+    """The forward cell's program at one sequence (batch 1, where the CPU's
+    compiler drops the products' metadata): compiled for the chip, every
+    product of the attention core (named by its einsum) carries the
+    ``attention`` scope, and no projection's does."""
+    import dataclasses
+    import re
+    from repro.configs import registry as cr
+    from repro.models import attention as A
+    from repro.models import registry as mr
+    cfg = dataclasses.replace(cr.reduced("qwen2-0.5b"),
+                              compute_dtype="bfloat16")
+    model = mr.build(cfg)
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    text = jax.jit(model.forward).lower(params, jax.ShapeDtypeStruct(
+        (1, 512), jnp.int32, sharding=one_chip)).compile().as_text()
+    core, other = [], []
+    for line in text.splitlines():
+        m = re.search(r' (?:dot|convolution)\(.*op_name="([^"]*)"', line)
+        if m:
+            path = m.group(1).split("/")
+            scoped = A.SCOPE in path
+            (core if "->" in path[-2] else other).append(scoped)
+    assert core and other
+    assert all(core) and not any(other)
