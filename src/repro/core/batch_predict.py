@@ -28,7 +28,8 @@ instead of ``len(grid)`` Python op-graph walks.  Memory-bound ops keep the
 scalar path's EXACT proxy features, which come from a jitted-snippet
 ``cost_analysis`` per unique (snippet, shape, dtype) — the first sweep over
 new shapes pays that XLA-compile cost (lru-cached thereafter), the same
-cost the looped scalar predictor pays; steady-state sweeps are pure numpy.
+cost the looped scalar predictor pays, with one call's missing snippets
+compiled side by side on threads; steady-state sweeps are pure numpy.
 
 ``PredictionCache`` is an LRU + JSON-persistent prediction cache keyed on
 ``(model, device, dtype, batch, seq)``; ``predict_model_cached`` and
@@ -45,9 +46,11 @@ import json
 import os
 import zlib
 from collections import Counter, OrderedDict
-from typing import Dict, List, Optional, Sequence, Tuple, Union
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, Iterable, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs import base as C
 from repro.core import collectives as CC
@@ -318,6 +321,8 @@ class BatchPredictor:
         # grid sweeps so steady-state cost never depends on (and cannot
         # thrash) opgraph._snippet_features' bounded lru_cache
         self._feat_cache: Dict[tuple, np.ndarray] = {}
+        # batches of missing rows compiled together, counted by their size
+        self._feat_batches: Counter = Counter()
         # fleet: derived predictors over roofline-transferred stores,
         # one per target device (core/transfer.py), built lazily
         self._fleet: Dict[str, "BatchPredictor"] = {}
@@ -352,6 +357,7 @@ class BatchPredictor:
             # share the proxy-feature rows: cost_analysis features are
             # device-independent inputs to the (rescaled) memory model
             derived._feat_cache = self._feat_cache
+            derived._feat_batches = self._feat_batches
             self._fleet[device] = derived
         return derived
 
@@ -474,23 +480,36 @@ class BatchPredictor:
             return np.asarray(mmod.class_coef[cls])
         return np.asarray(mmod.coef)
 
-    def _feature_row(self, snippet: str, shape: tuple, dtype: str) -> np.ndarray:
-        fkey = (snippet, tuple(shape), dtype)
-        row = self._feat_cache.get(fkey)
-        if row is None:
-            row = feature_vector(og._snippet_features(snippet, tuple(shape),
-                                                      dtype))
-            self._feat_cache[fkey] = row
-        return row
+    def _feature_rows(self, keys: Iterable[tuple]) -> None:
+        """Puts the proxy-feature row of every ``(snippet, shape, dtype)``
+        key into ``_feat_cache``.  The missing keys' snippets compile side
+        by side on threads: each compile is host-side XLA work that depends
+        on no other key, so a query waits for about its slowest compile
+        instead of their sum.  A worker's exception reaches the caller."""
+        missing = list(dict.fromkeys(
+            k for k in keys if k not in self._feat_cache))
+        if not missing:
+            return
+        self._feat_batches[len(missing)] += 1
+        with TraceAnnotation("predict.snippet_batch", n=len(missing)):
+            if len(missing) == 1:
+                feats = [og._snippet_features(*missing[0])]
+            else:
+                with ThreadPoolExecutor(
+                        min(len(missing), os.cpu_count() or 1)) as pool:
+                    feats = list(pool.map(
+                        lambda key: og._snippet_features(*key), missing))
+        self._feat_cache.update(zip(missing, map(feature_vector, feats)))
 
     def predict_memory_batch(self, ops: Sequence) -> np.ndarray:
         """Seconds for a batch of ``MemoryOp``s: one stacked feature-matrix
         product through the per-class linear coefficients."""
         if not ops:
             return np.zeros(0)
+        keys = [(op.snippet, tuple(op.shape), op.dtype) for op in ops]
+        self._feature_rows(keys)
         X = self.memory_model.apply_cache(
-            np.stack([self._feature_row(op.snippet, op.shape, op.dtype)
-                      for op in ops]))
+            np.stack([self._feat_cache[k] for k in keys]))
         Cm = np.stack([self._memory_coef(op.snippet) for op in ops])
         counts = np.array([op.count for op in ops], np.float64)
         return (X * Cm).sum(axis=1) * counts
@@ -724,13 +743,13 @@ class BatchPredictor:
                                                   dtype=dtype).sum(axis=0)
         mem = [op for op in gops if isinstance(op, _GMem)]
         if mem:
-            X = np.empty((len(mem), G, 4))
-            for i, op in enumerate(mem):
-                for g in range(G):
-                    shape = tuple(int(x[g]) if isinstance(x, np.ndarray)
-                                  else int(x) for x in op.shape)
-                    X[i, g] = self._feature_row(op.snippet, shape, op.dtype)
-            X = self.memory_model.apply_cache(X)
+            keys = [[(op.snippet,
+                      tuple(int(x[g]) if isinstance(x, np.ndarray)
+                            else int(x) for x in op.shape), op.dtype)
+                     for g in range(G)] for op in mem]
+            self._feature_rows(k for row in keys for k in row)
+            X = self.memory_model.apply_cache(np.array(
+                [[self._feat_cache[k] for k in row] for row in keys]))
             Cm = np.stack([self._memory_coef(op.snippet) for op in mem])
             counts = np.stack(
                 [np.broadcast_to(_f64(op.count), (G,)) for op in mem])

@@ -968,8 +968,13 @@ class LatencyService:
 
     @property
     def stats(self) -> dict:
-        """The cache's counts, and ``snippet_compiles``: the memory snippets
+        """The cache's counts; ``snippet_compiles``: the memory snippets
         compiled so far, the calls ``opgraph._snippet_features``' cache did
-        not answer (process-wide, as that cache is)."""
+        not answer (process-wide, as that cache is); and
+        ``snippet_compile_batches``: the batches in which this service's
+        predictors compiled the snippets a pricing call lacked, together,
+        so compiles over batches is the overlap they got."""
         return dict(self.cache.stats, snippet_compiles=(
-            opgraph._snippet_features.cache_info().misses))
+            opgraph._snippet_features.cache_info().misses),
+            snippet_compile_batches=sum(
+                self.predictor._feat_batches.values()))

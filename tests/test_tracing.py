@@ -1,7 +1,8 @@
 """The program's own marks for the profiler: the ``attention`` name scope on
 the compiled step's attention ops, the ``latency.*`` and
-``predict.snippet_compile`` spans with the compile counter behind
-``LatencyService.stats``, and the per-family split of each answer."""
+``predict.snippet_compile`` and ``predict.snippet_batch`` spans with the
+counters behind ``LatencyService.stats``, and the per-family split of each
+answer."""
 import dataclasses
 import glob
 import re
@@ -115,10 +116,15 @@ def test_query_spans_and_compile_counter(calibration_store, tmp_path):
     assert not first.cached and again.cached and not other.cached
 
     events = _host_events(tmp_path, {"latency.latency_train",
-                                     "predict.snippet_compile"})
+                                     "predict.snippet_compile",
+                                     "predict.snippet_batch"})
     spans = [s for n, s in events if n == "latency.latency_train"]
     compiles = [s for n, s in events if n == "predict.snippet_compile"]
+    batches = [s for n, s in events if n == "predict.snippet_batch"]
     assert len(compiles) == new - before
+    # one batch per cold service, each compiling what it lacked together
+    assert [s["n"] for s in batches] == [new - before, new - before]
+    assert svc.stats["snippet_compile_batches"] == 1
     # a one-dimensional shape reads back as a number
     assert all(s["snippet"] in og.SNIPPETS
                and re.fullmatch(r"\d+(x\d+)*", str(s["shape"]))
